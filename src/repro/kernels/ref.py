@@ -27,15 +27,22 @@ def _hash_u32(seed, idx):
     return x
 
 
+def _top24(h):
+    """The top 24 bits of uint32 ``h`` as exact f32 values in [0, 2^24)."""
+    return (h >> 8).astype(jnp.int32).astype(jnp.float32)
+
+
 def counter_gauss(seed: jnp.ndarray, idx: jnp.ndarray) -> jnp.ndarray:
     """Standard normal from two independent hashes via Box-Muller (f32)."""
     seed = jnp.asarray(seed, jnp.uint32)
     idx = jnp.asarray(idx, jnp.uint32)
     h1 = _hash_u32(seed, idx)
     h2 = _hash_u32(seed ^ np.uint32(0xA5A5A5A5), idx)
+    # uniforms from the top 24 bits, which f32 holds exactly; the cast goes
+    # through int32 because Mosaic has no uint32 -> f32 conversion.
     # u1 in (0,1]: avoid log(0); u2 in [0,1)
-    u1 = (h1.astype(jnp.float32) + 1.0) * (1.0 / 4294967296.0)
-    u2 = h2.astype(jnp.float32) * (1.0 / 4294967296.0)
+    u1 = (_top24(h1) + 1.0) * (1.0 / 16777216.0)
+    u2 = _top24(h2) * (1.0 / 16777216.0)
     r = jnp.sqrt(-2.0 * jnp.log(u1))
     return r * jnp.cos(2.0 * jnp.float32(jnp.pi) * u2)
 

@@ -4,7 +4,7 @@ The ZO training hot loop sweeps every parameter 2τ+3 times per round with
 ``x ± λu`` / ``x ← x − a·u``. A naive implementation reads x AND a
 materialized u from HBM (two reads + one write). These kernels regenerate u
 *inside VMEM* from a counter-based hash (murmur3 finalizer + Box-Muller —
-identical formula to ref.counter_gauss), making the op one HBM read + one
+the formula of ref.counter_gauss), making the op one HBM read + one
 write (1.5× traffic reduction) and eliminating parameter-sized noise
 storage entirely — the TPU realization of MeZO-style seed replay adapted to
 the HBM→VMEM hierarchy.
@@ -31,38 +31,13 @@ import functools
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-LANE = 1024          # elements per row (8 × 128 VREG tiles)
+# the oracle's counter layout and noise formula, traced in-kernel
+from repro.kernels.ref import LANE, counter_gauss2
+
 BLOCK_ROWS = 256     # rows per grid step: 256 × 1024 × 4 B = 1 MiB VMEM
-
-_M1 = np.uint32(0x85EBCA6B)
-_M2 = np.uint32(0xC2B2AE35)
-_GOLD = np.uint32(0x9E3779B9)
-# LANE must stay in sync with kernels/ref.py (shared counter layout)
-
-
-def _hash_u32(seed, idx):
-    x = (idx * _GOLD + seed).astype(jnp.uint32)
-    x = x ^ (x >> 16)
-    x = (x * _M1).astype(jnp.uint32)
-    x = x ^ (x >> 13)
-    x = (x * _M2).astype(jnp.uint32)
-    x = x ^ (x >> 16)
-    return x
-
-
-def _gauss2(seed, hi, lo):
-    """2-D counter gaussian — identical formula to ref.counter_gauss2."""
-    mixed = (hi * _M1 + seed).astype(jnp.uint32)
-    h1 = _hash_u32(mixed, lo)
-    h2 = _hash_u32(mixed ^ np.uint32(0xA5A5A5A5), lo)
-    u1 = (h1.astype(jnp.float32) + 1.0) * (1.0 / 4294967296.0)
-    u2 = h2.astype(jnp.float32) * (1.0 / 4294967296.0)
-    return jnp.sqrt(-2.0 * jnp.log(u1)) * jnp.cos(
-        2.0 * jnp.float32(np.pi) * u2)
 
 
 def _zo_update_kernel(seed_ref, coeff_ref, x_ref, o_ref, *, offset: int):
@@ -71,7 +46,7 @@ def _zo_update_kernel(seed_ref, coeff_ref, x_ref, o_ref, *, offset: int):
     row0 = jnp.uint32(offset) + jnp.uint32(i) * jnp.uint32(rows)
     hi = row0 + jax.lax.broadcasted_iota(jnp.uint32, (rows, lane), 0)
     lo = jax.lax.broadcasted_iota(jnp.uint32, (rows, lane), 1)
-    u = _gauss2(seed_ref[0], hi, lo)
+    u = counter_gauss2(seed_ref[0], hi, lo)
     x = x_ref[...].astype(jnp.float32)
     o_ref[...] = (x + coeff_ref[0] * u).astype(o_ref.dtype)
 
@@ -110,7 +85,7 @@ def _zo_replay_kernel(seeds_ref, coeffs_ref, x_ref, o_ref, *, offset: int,
     lo = jax.lax.broadcasted_iota(jnp.uint32, (rows, lane), 1)
 
     def body(j, acc):
-        return acc + coeffs_ref[j] * _gauss2(seeds_ref[j], hi, lo)
+        return acc + coeffs_ref[j] * counter_gauss2(seeds_ref[j], hi, lo)
 
     acc = jax.lax.fori_loop(0, n, body, jnp.zeros((rows, lane), jnp.float32))
     o_ref[...] = (x_ref[...].astype(jnp.float32) + acc).astype(o_ref.dtype)

@@ -11,7 +11,7 @@ else (tests/benches see 1 device).
 Usage:
     PYTHONPATH=src python -m repro.launch.dryrun                    # all cells
     PYTHONPATH=src python -m repro.launch.dryrun --arch olmo-1b \
-        --shape train_4k --multi-pod --out /tmp/dryrun.json
+        --shape train_4k --multi-pod --out dryrun.json
 """
 import argparse
 import json
@@ -80,7 +80,7 @@ def main(argv=None):
     ap.add_argument("--tau", type=int, default=2)
     ap.add_argument("--aggregation", default="dense",
                     choices=["dense", "seed_replay"])
-    ap.add_argument("--out", default="/root/repo/dryrun_results.json")
+    ap.add_argument("--out", default="dryrun_results.json")
     ap.add_argument("--append", action="store_true")
     args = ap.parse_args(argv)
 

@@ -28,6 +28,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.configs.base import MeshConfig, SFLConfig
 from repro.core import events
+from repro.launch.mesh import make_mesh
 from repro.obs.trace import span
 from repro.core.population import ClientPopulation
 from repro.sharding.planner import EventStorePlan, plan_event_store
@@ -97,7 +98,7 @@ def build_fleet_placement(sfl: SFLConfig, *,
         if n > len(jax.devices()):
             raise ValueError(f"data_devices={n} exceeds the "
                              f"{len(jax.devices())} available devices")
-        mesh = jax.make_mesh((n,), ("data",))
+        mesh = make_mesh((n,), ("data",))
     if "data" not in mesh.axis_names:
         raise ValueError(f"fleet placement needs a 'data' mesh axis; got "
                          f"{mesh.axis_names}")

@@ -30,6 +30,7 @@ from repro.configs import SFLConfig, get_config
 from repro.core import engine, events
 from repro.core import straggler as strag
 from repro.data import FederatedLoader, SyntheticLM, dirichlet_partition
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import init_params, untie_params
 
 
@@ -175,6 +176,7 @@ def main(argv=None):
     ap.add_argument("--lr-server", type=float, default=1e-3)
     ap.add_argument("--lr-client", type=float, default=5e-4)
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     if args.run_async:
         if args.loop is not None:
@@ -443,6 +445,13 @@ def main(argv=None):
                          if args.loader == "subset" else None),
         batch_put=placement.batch_put if placement is not None else None,
         telemetry=sink)
+    if placement is not None:
+        # where the donated ring store ended up after the last chunk
+        devices = {d for x in jax.tree.leaves(result.state)
+                   for d in x.sharding.device_set}
+        specs = {k: str(v.sharding.spec) for k, v in result.state.items()}
+        print(f"fleet placement: final ring store on {len(devices)} "
+              f"devices {specs}")
     if controller is not None and controller.trace:
         vals = [t for _, t in controller.trace]
         if args.adaptive_quorum:

@@ -5,8 +5,11 @@ the dry-run machinery lowers/compiles on small meshes."""
 import json
 import subprocess
 import sys
+from pathlib import Path
 
 import pytest
+
+REPO = Path(__file__).resolve().parents[1]
 
 SCRIPT = r"""
 import os
@@ -62,5 +65,5 @@ print("DISTRIBUTED_OK", diff)
 @pytest.mark.slow
 def test_sharded_round_matches_single_device():
     r = subprocess.run([sys.executable, "-c", SCRIPT], capture_output=True,
-                       text=True, timeout=560, cwd="/root/repo")
+                       text=True, timeout=560, cwd=REPO)
     assert "DISTRIBUTED_OK" in r.stdout, r.stdout + "\n" + r.stderr[-3000:]

@@ -7,8 +7,19 @@ import signal
 import subprocess
 import sys
 import tempfile
+from pathlib import Path
 
 import pytest
+
+REPO = Path(__file__).resolve().parents[1]
+
+
+def _env(**overrides):
+    """This process's environment (JAX_PLATFORMS included) with the repo's
+    sources on PYTHONPATH, plus ``overrides``."""
+    path = os.pathsep.join(p for p in (str(REPO / "src"),
+                                       os.environ.get("PYTHONPATH")) if p)
+    return {**os.environ, "PYTHONPATH": path, **overrides}
 
 
 @pytest.mark.slow
@@ -17,12 +28,12 @@ def test_train_driver_checkpoint_restart():
         base = [sys.executable, "-m", "repro.launch.train", "--arch",
                 "olmo-1b", "--smoke", "--clients", "2", "--batch", "1",
                 "--seq", "16", "--ckpt-dir", d, "--ckpt-every", "2"]
-        env = {"PYTHONPATH": "src", "PATH": "/usr/bin:/bin"}
+        env = _env()
         r1 = subprocess.run(base + ["--rounds", "3"], capture_output=True,
-                            text=True, timeout=560, cwd="/root/repo", env=env)
+                            text=True, timeout=560, cwd=REPO, env=env)
         assert "round    2" in r1.stdout, r1.stdout + r1.stderr[-2000:]
         r2 = subprocess.run(base + ["--rounds", "5"], capture_output=True,
-                            text=True, timeout=560, cwd="/root/repo", env=env)
+                            text=True, timeout=560, cwd=REPO, env=env)
         assert "[resume] from round" in r2.stdout, r2.stdout + r2.stderr[-2000:]
         assert "round    4" in r2.stdout
 
@@ -36,7 +47,7 @@ def test_train_driver_sigkill_and_resume_bit_identical():
     boundary and produce a per-round loss log bit-identical to an
     uninterrupted run."""
     with tempfile.TemporaryDirectory() as d:
-        env = {"PYTHONPATH": "src", "PATH": "/usr/bin:/bin"}
+        env = _env()
 
         def cmd(tag, *extra):
             return [sys.executable, "-m", "repro.launch.train", "--arch",
@@ -46,12 +57,12 @@ def test_train_driver_sigkill_and_resume_bit_identical():
                     "--log-jsonl", f"{d}/{tag}.jsonl", *extra]
 
         ref = subprocess.run(cmd("ref"), capture_output=True, text=True,
-                             timeout=560, cwd="/root/repo", env=env)
+                             timeout=560, cwd=REPO, env=env)
         assert "round    7" in ref.stdout, ref.stdout + ref.stderr[-2000:]
 
         killed = subprocess.run(cmd("kill", "--faults", "kill=5"),
                                 capture_output=True, text=True, timeout=560,
-                                cwd="/root/repo", env=env)
+                                cwd=REPO, env=env)
         assert killed.returncode == -signal.SIGKILL, \
             killed.stdout + killed.stderr[-2000:]
         assert "[faults] kill=5: SIGKILL after chunk [4, 6)" in killed.stdout
@@ -62,7 +73,7 @@ def test_train_driver_sigkill_and_resume_bit_identical():
         assert steps[-1] == "step_0000000003", steps
 
         resumed = subprocess.run(cmd("kill"), capture_output=True,
-                                 text=True, timeout=560, cwd="/root/repo",
+                                 text=True, timeout=560, cwd=REPO,
                                  env=env)
         assert "[resume] from round 4" in resumed.stdout, \
             resumed.stdout + resumed.stderr[-2000:]
@@ -136,9 +147,8 @@ def test_train_driver_rejects_indivisible_fleet_geometry():
          "--seq", "16", "--async", "--quorum", "2", "--timeline",
          "sparse", "--k-max", "6", "--ring-capacity", "6",
          "--fleet-shard", "4"],
-        capture_output=True, text=True, timeout=560, cwd="/root/repo",
-        env={"PYTHONPATH": "src", "PATH": "/usr/bin:/bin",
-             "XLA_FLAGS": "--xla_force_host_platform_device_count=4"})
+        capture_output=True, text=True, timeout=560, cwd=REPO,
+        env=_env(XLA_FLAGS="--xla_force_host_platform_device_count=4"))
     assert r.returncode != 0
     assert "does not divide the 'data' axis" in r.stderr, r.stderr[-2000:]
 
@@ -171,7 +181,6 @@ def test_train_driver_sharded_run_matches_unsharded():
         "print('SHARDED_OK', ds, dh)\n")
     r = subprocess.run(
         [sys.executable, "-c", script], capture_output=True, text=True,
-        timeout=560, cwd="/root/repo",
-        env={"PYTHONPATH": "src", "PATH": "/usr/bin:/bin",
-             "XLA_FLAGS": "--xla_force_host_platform_device_count=4"})
+        timeout=560, cwd=REPO,
+        env=_env(XLA_FLAGS="--xla_force_host_platform_device_count=4"))
     assert "SHARDED_OK" in r.stdout, r.stdout + r.stderr[-2000:]
