@@ -86,23 +86,28 @@ def gas_round(cfg: ModelConfig, sfl: SFLConfig, params: Params, state: GasState,
         ukey = jax.random.fold_in(k, 0)
         skey = jax.random.fold_in(k, 1)
         # fresh clients compute new messages; stale reuse the buffer
-        h_new = client_forward(cfg, xc, b_new)
+        with jax.named_scope("sfl.client_forward"):
+            h_new = client_forward(cfg, xc, b_new)
+            hp = client_forward(cfg, zo.perturb(xc, ukey, +sfl.zo_eps,
+                                                sfl.perturbation_dist), b_new)
+            hm = client_forward(cfg, zo.perturb(xc, ukey, -sfl.zo_eps,
+                                                sfl.perturbation_dist), b_new)
         h = jax.tree.map(lambda a, o: jnp.where(fresh > 0, a, o), h_new, h_old)
         b_used = jax.tree.map(lambda a, o: jnp.where(fresh > 0, a, o),
                               b_new, b_old)
-        hp = client_forward(cfg, zo.perturb(xc, ukey, +sfl.zo_eps,
-                                            sfl.perturbation_dist), b_new)
-        hm = client_forward(cfg, zo.perturb(xc, ukey, -sfl.zo_eps,
-                                            sfl.perturbation_dist), b_new)
-        loss0 = server_forward(cfg, xs, h, b_used)
+        with jax.named_scope("sfl.server_eval"):
+            loss0 = server_forward(cfg, xs, h, b_used)
 
         def loss_of(sp):
             return server_forward(cfg, sp, h, b_used)
-        sp_new, delta, (skeys, scoeffs) = zo.spsa_step(
-            loss_of, xs, skey, sfl.zo_eps, sfl.lr_server,
-            sfl.n_perturbations, sfl.perturbation_dist, replay=replay)
-        delta_c = (server_forward(cfg, sp_new, hp, b_new)
-                   - server_forward(cfg, sp_new, hm, b_new)).astype(jnp.float32)
+        with jax.named_scope("sfl.server_tau"):
+            sp_new, delta, (skeys, scoeffs) = zo.spsa_step(
+                loss_of, xs, skey, sfl.zo_eps, sfl.lr_server,
+                sfl.n_perturbations, sfl.perturbation_dist, replay=replay)
+        with jax.named_scope("sfl.zo_backprop"):
+            delta_c = (server_forward(cfg, sp_new, hp, b_new)
+                       - server_forward(cfg, sp_new, hm, b_new)
+                       ).astype(jnp.float32)
         ccoeff = fresh * sfl.lr_client * delta_c / (2.0 * sfl.zo_eps)
         return {"xs_final": sp_new, "h": h, "b": b_used, "ukey": ukey,
                 "ccoeff": ccoeff, "loss0": loss0, "delta": delta,
@@ -112,19 +117,20 @@ def gas_round(cfg: ModelConfig, sfl: SFLConfig, params: Params, state: GasState,
                                mkeys, fresh_mask)
     w = jnp.full((M,), 1.0 / M, jnp.float32)
 
-    if aggregation == "dense":
-        def agg(g, stacked):
-            d = jnp.tensordot(w, (stacked - g[None]).astype(jnp.float32),
-                              axes=1)
-            return (g + sfl.lr_global * d).astype(g.dtype)
-        xs_new = jax.tree.map(agg, xs, out["xs_final"])
-    else:  # seed_replay: flatten the (M, P) server records, weight by η_g·w_m
-        xs_new = zo.replay_weighted_records(
-            xs, out["skeys"], out["scoeffs"], sfl.lr_global * w,
+    with jax.named_scope("sfl.replay"):
+        if aggregation == "dense":
+            def agg(g, stacked):
+                d = jnp.tensordot(w, (stacked - g[None]).astype(jnp.float32),
+                                  axes=1)
+                return (g + sfl.lr_global * d).astype(g.dtype)
+            xs_new = jax.tree.map(agg, xs, out["xs_final"])
+        else:  # seed_replay: the (M, P) server records, weighted η_g·w_m
+            xs_new = zo.replay_weighted_records(
+                xs, out["skeys"], out["scoeffs"], sfl.lr_global * w,
+                sfl.perturbation_dist, impl=replay)
+        xc_new = zo.replay_weighted_records(
+            xc, out["ukey"], out["ccoeff"], sfl.lr_global * w,
             sfl.perturbation_dist, impl=replay)
-    xc_new = zo.replay_weighted_records(
-        xc, out["ukey"], out["ccoeff"], sfl.lr_global * w,
-        sfl.perturbation_dist, impl=replay)
     new_state = GasState(h_buffer=out["h"], label_buffer=out["b"])
     metrics = RoundMetrics(loss=out["loss0"],
                            server_deltas=out["delta"][:, None],

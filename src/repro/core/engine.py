@@ -710,19 +710,21 @@ def _tree_nbytes(tree) -> int:
 
 
 def _cached_jit(algo: Algorithm, mode: str, cfg: ModelConfig, sfl: SFLConfig,
-                build: Callable):
+                build: Callable) -> Tuple[Callable, bool]:
     """Per-algorithm-instance jit cache: repeated run_rounds calls with the
     same (algo, cfg, sfl) reuse the compiled executables instead of
     re-tracing a fresh closure every call (jax.jit caches by function
-    identity, which a fresh lambda defeats)."""
+    identity, which a fresh lambda defeats). Returns (fn, built): built is
+    True when this call made fn, so its first call traces and compiles."""
     cache = getattr(algo, "_engine_jit_cache", None)
     if cache is None:
         cache = {}
         object.__setattr__(algo, "_engine_jit_cache", cache)
     k = (mode, cfg, sfl)
-    if k not in cache:
+    built = k not in cache
+    if built:
         cache[k] = build()
-    return cache[k]
+    return cache[k], built
 
 
 def _has_state(state) -> bool:
@@ -907,6 +909,10 @@ def run_rounds(algorithm: Union[str, Algorithm], cfg: ModelConfig,
         return EngineResult(params, state, {}, empty, empty, 0.0,
                             np.zeros((0,), np.int64))
 
+    # host work up to the first chunk, closed before the chunk loop (an
+    # exception before then leaves it open: run_rounds has failed anyway)
+    prepare = span("engine.prepare", start=start_round, stop=rounds)
+    prepare.__enter__()
     if state is None:
         # the subset path never materializes a fleet-width batch, not even
         # for the state template: sparse-capable algorithms size their
@@ -1205,36 +1211,43 @@ def run_rounds(algorithm: Union[str, Algorithm], cfg: ModelConfig,
                                                  sched_eff)
         tau_used[i:] = sfl.tau
 
+    if mode != "python":
+        params, state = _copy_tree(params), _copy_tree(state)
+    prepare.__exit__(None, None, None)
+
     if mode == "python":
         for si, (r0, r1) in enumerate(segments):
-            if controller is not None:
-                controller_step(si)
-            round_jit = _cached_jit(
-                algo, "python", cfg, sfl,
-                lambda sfl=sfl: jax.jit(lambda p, s, b, m, k: algo.round_fn(
-                    cfg, sfl, p, s, b, m, k)))
-            t_seg = perf_counter() if telemetry is not None else 0.0
-            for rr in range(r0, r1):
-                i = rr - start_round
-                b = jax.tree.map(jnp.asarray, batch_fn(rr))
-                params, state, met = round_jit(params, state, b,
-                                               jnp.asarray(masks[i]), keys[i])
-                flush(jax.tree.map(lambda a: a[None], met), rr, rr + 1)
-                if (checkpointer is not None and ckpt_every
-                        and (rr + 1) % ckpt_every == 0 and rr + 1 < rounds):
-                    checkpointer.save(rr, _ckpt_tree(params, state),
-                                      metadata=ckpt_meta())
-            if telemetry is not None:
-                # per-round flush above is the host sync, so the segment
-                # bracket needs no extra block_until_ready
-                dt, C = perf_counter() - t_seg, r1 - r0
-                telemetry.emit(RoundTelemetry(
-                    r0, r1, "measured", mode, np.full(C, dt / C),
-                    dispatch_seconds=dt))
-            if controller is not None and r1 - r0 > 1:
-                # controllers see the whole segment's metrics, exactly as
-                # in scan mode (flush above is per round here)
-                last_info = seg_info(r0, r1)
+            with span("engine.chunk", start=r0, stop=r1):
+                if controller is not None:
+                    controller_step(si)
+                round_jit, _ = _cached_jit(
+                    algo, "python", cfg, sfl,
+                    lambda sfl=sfl: jax.jit(
+                        lambda p, s, b, m, k: algo.round_fn(
+                            cfg, sfl, p, s, b, m, k)))
+                t_seg = perf_counter() if telemetry is not None else 0.0
+                for rr in range(r0, r1):
+                    i = rr - start_round
+                    b = jax.tree.map(jnp.asarray, batch_fn(rr))
+                    params, state, met = round_jit(
+                        params, state, b, jnp.asarray(masks[i]), keys[i])
+                    flush(jax.tree.map(lambda a: a[None], met), rr, rr + 1)
+                    if (checkpointer is not None and ckpt_every
+                            and (rr + 1) % ckpt_every == 0
+                            and rr + 1 < rounds):
+                        checkpointer.save(rr, _ckpt_tree(params, state),
+                                          metadata=ckpt_meta())
+                if telemetry is not None:
+                    # per-round flush above is the host sync, so the
+                    # segment bracket needs no extra block_until_ready
+                    dt, C = perf_counter() - t_seg, r1 - r0
+                    telemetry.emit(RoundTelemetry(
+                        r0, r1, "measured", mode, np.full(C, dt / C),
+                        dispatch_seconds=dt))
+                if controller is not None and r1 - r0 > 1:
+                    # controllers see the whole segment's metrics, exactly
+                    # as in scan mode (flush above is per round here)
+                    last_info = seg_info(r0, r1)
     else:
         # fused on-device modes: 'scan' over schedule rows, dense 'async'
         # over the compiled timeline's (start_mask, apply_w) rows, sparse
@@ -1242,85 +1255,90 @@ def run_rounds(algorithm: Union[str, Algorithm], cfg: ModelConfig,
         # modes differ only in the chunk body and its scanned inputs
         make_fn = (make_sparse_chunk_fn if sparse else
                    make_async_chunk_fn if mode == "async" else make_chunk_fn)
-        params, state = _copy_tree(params), _copy_tree(state)
         pending_rows: Optional[events.SparseRows] = None
         tele = telemetry is not None
         for si, (r0, r1) in enumerate(segments):
-            if controller is not None:
-                controller_step(si)
-            chunk_jit = _cached_jit(
-                algo, mode, cfg, sfl,
-                lambda sfl=sfl: jax.jit(make_fn(algo, cfg, sfl),
-                                        donate_argnums=(0, 1)))
-            i, C = r0 - start_round, r1 - r0
-            # measured-producer bracketing: host staging is [t_host,
-            # t_disp), the device chunk is [t_disp, t_sync) closed by
-            # block_until_ready — the DES prefetch stays INSIDE that
-            # dispatch window (that's the overlap being measured), never
-            # after it, so turning telemetry on cannot serialize the
-            # host/device pipeline it is measuring.
-            t_host = perf_counter() if tele else 0.0
-            overlap = 0.0
-            if sparse:
-                with span("engine.des_take", start=r0, stop=r1):
-                    rows_c = (pending_rows if pending_rows is not None
-                              else stream.take(C))
-                pending_rows = None
-                masks[i:i + C] = rows_c.apply_w
-                round_times[i:i + C] = rows_c.durations
-                qwaits[i:i + C] = rows_c.quorum_wait
-                for j, f in enumerate(fault_cols):
-                    fcounts[i:i + C, j] = getattr(rows_c, f)
-                with span("engine.stage", start=r0, stop=r1):
-                    staged = _stack_sparse_chunk(
-                        batch_fn, r0, rows_c.start_client,
-                        subset_fn=batch_subset_fn, batch_put=batch_put)
-                t_disp = perf_counter() if tele else 0.0
-                with span("engine.dispatch", start=r0, stop=r1):
-                    params, state, mets = chunk_jit(
-                        params, state, staged,
-                        jnp.asarray(rows_c.start_client),
-                        jnp.asarray(rows_c.start_slot),
-                        jnp.asarray(rows_c.apply_slot),
-                        jnp.asarray(rows_c.apply_w), keys[i:i + C])
-                if controller is None and si + 1 < len(segments):
-                    # host/device overlap: JAX dispatch is async, so the
-                    # DES generates the NEXT chunk's events while the
-                    # device still scans this one (flush below is the
-                    # host-sync point). Controller runs can't prefetch —
-                    # the next boundary may rebuild the stream.
-                    n0, n1 = segments[si + 1]
-                    t_pre = perf_counter() if tele else 0.0
-                    with span("engine.des_prefetch", start=n0, stop=n1):
-                        pending_rows = stream.take(n1 - n0)
-                    if tele:
-                        overlap = perf_counter() - t_pre
-            else:
-                with span("engine.stage", start=r0, stop=r1):
-                    staged = _stack_chunk(batch_fn, r0, C)
-                extra = ((jnp.asarray(start_masks[i:i + C]),)
-                         if mode == "async" else ())
-                t_disp = perf_counter() if tele else 0.0
-                with span("engine.dispatch", start=r0, stop=r1):
-                    params, state, mets = chunk_jit(
-                        params, state, staged, *extra,
-                        jnp.asarray(masks[i:i + C]), keys[i:i + C])
-            if tele:
-                jax.block_until_ready(mets)
-                t_sync = perf_counter()
-                telemetry.emit(RoundTelemetry(
-                    r0, r1, "measured", mode,
-                    np.full(C, (t_sync - t_disp) / C),
-                    staging_seconds=t_disp - t_host,
-                    staging_bytes=_tree_nbytes(staged),
-                    dispatch_seconds=t_sync - t_disp,
-                    overlap_seconds=overlap))
-            with span("engine.flush", start=r0, stop=r1):
-                flush(mets, r0, r1)
-            if (checkpointer is not None and ckpt_every
-                    and r1 % ckpt_every == 0 and r1 < rounds):
-                checkpointer.save(r1 - 1, _ckpt_tree(params, state),
-                                  metadata=ckpt_meta())
+            with span("engine.chunk", start=r0, stop=r1):
+                if controller is not None:
+                    controller_step(si)
+                chunk_jit, new_program = _cached_jit(
+                    algo, mode, cfg, sfl,
+                    lambda sfl=sfl: jax.jit(make_fn(algo, cfg, sfl),
+                                            donate_argnums=(0, 1)))
+                i, C = r0 - start_round, r1 - r0
+                # measured-producer bracketing: host staging is [t_host,
+                # t_disp), the device chunk is [t_disp, t_sync) closed by
+                # block_until_ready — the DES prefetch stays INSIDE that
+                # dispatch window (that's the overlap being measured),
+                # never after it, so turning telemetry on cannot serialize
+                # the host/device pipeline it is measuring. A dispatch with
+                # new_program=1 traces and compiles its chunk function.
+                t_host = perf_counter() if tele else 0.0
+                overlap = 0.0
+                if sparse:
+                    with span("engine.des_take", start=r0, stop=r1):
+                        rows_c = (pending_rows if pending_rows is not None
+                                  else stream.take(C))
+                    pending_rows = None
+                    masks[i:i + C] = rows_c.apply_w
+                    round_times[i:i + C] = rows_c.durations
+                    qwaits[i:i + C] = rows_c.quorum_wait
+                    for j, f in enumerate(fault_cols):
+                        fcounts[i:i + C, j] = getattr(rows_c, f)
+                    with span("engine.stage", start=r0, stop=r1):
+                        staged = _stack_sparse_chunk(
+                            batch_fn, r0, rows_c.start_client,
+                            subset_fn=batch_subset_fn, batch_put=batch_put)
+                    t_disp = perf_counter() if tele else 0.0
+                    with span("engine.dispatch", start=r0, stop=r1,
+                              new_program=int(new_program)):
+                        params, state, mets = chunk_jit(
+                            params, state, staged,
+                            jnp.asarray(rows_c.start_client),
+                            jnp.asarray(rows_c.start_slot),
+                            jnp.asarray(rows_c.apply_slot),
+                            jnp.asarray(rows_c.apply_w), keys[i:i + C])
+                    if controller is None and si + 1 < len(segments):
+                        # host/device overlap: JAX dispatch is async, so
+                        # the DES generates the NEXT chunk's events while
+                        # the device still scans this one (flush below is
+                        # the host-sync point). Controller runs can't
+                        # prefetch — the next boundary may rebuild the
+                        # stream.
+                        n0, n1 = segments[si + 1]
+                        t_pre = perf_counter() if tele else 0.0
+                        with span("engine.des_prefetch", start=n0,
+                                  stop=n1):
+                            pending_rows = stream.take(n1 - n0)
+                        if tele:
+                            overlap = perf_counter() - t_pre
+                else:
+                    with span("engine.stage", start=r0, stop=r1):
+                        staged = _stack_chunk(batch_fn, r0, C)
+                    extra = ((jnp.asarray(start_masks[i:i + C]),)
+                             if mode == "async" else ())
+                    t_disp = perf_counter() if tele else 0.0
+                    with span("engine.dispatch", start=r0, stop=r1,
+                              new_program=int(new_program)):
+                        params, state, mets = chunk_jit(
+                            params, state, staged, *extra,
+                            jnp.asarray(masks[i:i + C]), keys[i:i + C])
+                if tele:
+                    jax.block_until_ready(mets)
+                    t_sync = perf_counter()
+                    telemetry.emit(RoundTelemetry(
+                        r0, r1, "measured", mode,
+                        np.full(C, (t_sync - t_disp) / C),
+                        staging_seconds=t_disp - t_host,
+                        staging_bytes=_tree_nbytes(staged),
+                        dispatch_seconds=t_sync - t_disp,
+                        overlap_seconds=overlap))
+                with span("engine.flush", start=r0, stop=r1):
+                    flush(mets, r0, r1)
+                if (checkpointer is not None and ckpt_every
+                        and r1 % ckpt_every == 0 and r1 < rounds):
+                    checkpointer.save(r1 - 1, _ckpt_tree(params, state),
+                                      metadata=ckpt_meta())
 
     def _cat(k2):
         arrs = [c[k2] for c in chunks]
@@ -1332,13 +1350,14 @@ def run_rounds(algorithm: Union[str, Algorithm], cfg: ModelConfig,
                     for a in arrs]
         return np.concatenate(arrs)
 
-    metrics = {k2: _cat(k2) for k2 in chunks[0]}
-    loss = metrics["loss"]
-    round_loss = ((loss * masks).sum(1)
-                  / np.maximum(masks.sum(1), 1.0)).astype(np.float64)
-    if checkpointer is not None:
-        checkpointer.save(rounds - 1, _ckpt_tree(params, state),
-                          metadata=ckpt_meta(loss=float(round_loss[-1])),
-                          block=True)
+    with span("engine.finish", start=start_round, stop=rounds):
+        metrics = {k2: _cat(k2) for k2 in chunks[0]}
+        loss = metrics["loss"]
+        round_loss = ((loss * masks).sum(1)
+                      / np.maximum(masks.sum(1), 1.0)).astype(np.float64)
+        if checkpointer is not None:
+            checkpointer.save(rounds - 1, _ckpt_tree(params, state),
+                              metadata=ckpt_meta(loss=float(round_loss[-1])),
+                              block=True)
     return EngineResult(params, state, metrics, round_loss,
                         round_times, float(round_times.sum()), tau_used)

@@ -1214,11 +1214,15 @@ def async_mu_splitfed_step(cfg: ModelConfig, sfl: SFLConfig, params: Params,
 
     store = jax.tree.map(sel, fresh, store)
     w = (sfl.lr_global * apply_w).astype(jnp.float32)
-    xs_new = zo.replay_weighted_records(xs, store["srv_keys"],
-                                        store["srv_coeffs"], w,
-                                        sfl.perturbation_dist, impl=replay)
-    xc_new = zo.replay_weighted_records(xc, store["ukey"], store["ccoeff"],
-                                        w, sfl.perturbation_dist, impl=replay)
+    with jax.named_scope("sfl.replay"):
+        xs_new = zo.replay_weighted_records(xs, store["srv_keys"],
+                                            store["srv_coeffs"], w,
+                                            sfl.perturbation_dist,
+                                            impl=replay)
+        xc_new = zo.replay_weighted_records(xc, store["ukey"],
+                                            store["ccoeff"], w,
+                                            sfl.perturbation_dist,
+                                            impl=replay)
     metrics = {"loss": store["loss0"]}
     return merge_params(cfg, xc_new, xs_new), store, metrics
 
@@ -1258,11 +1262,14 @@ def async_mu_splitfed_sparse_step(cfg: ModelConfig, sfl: SFLConfig,
              for name, val in fresh.items()}
     w = (sfl.lr_global * apply_w).astype(jnp.float32)
     gather = lambda a: jnp.take(a, apply_slot, axis=0, mode="clip")
-    xs_new = zo.replay_weighted_records(xs, gather(store["srv_keys"]),
-                                        gather(store["srv_coeffs"]), w,
-                                        sfl.perturbation_dist, impl=replay)
-    xc_new = zo.replay_weighted_records(xc, gather(store["ukey"]),
-                                        gather(store["ccoeff"]), w,
-                                        sfl.perturbation_dist, impl=replay)
+    with jax.named_scope("sfl.replay"):
+        xs_new = zo.replay_weighted_records(xs, gather(store["srv_keys"]),
+                                            gather(store["srv_coeffs"]), w,
+                                            sfl.perturbation_dist,
+                                            impl=replay)
+        xc_new = zo.replay_weighted_records(xc, gather(store["ukey"]),
+                                            gather(store["ccoeff"]), w,
+                                            sfl.perturbation_dist,
+                                            impl=replay)
     metrics = {"loss": gather(store["loss0"])}
     return merge_params(cfg, xc_new, xs_new), store, metrics

@@ -31,6 +31,14 @@ Aggregation modes:
 The round function is pure/jit-able; straggler wall-clock simulation and
 participation decisions live outside (core/straggler.py) and enter here only
 through ``active_mask``.
+
+Each step of the round runs under a ``jax.named_scope`` that lands in the
+op_name metadata of its compiled ops, so a device trace splits a round's
+time by step: ``sfl.client_forward`` (the three client forwards and both
+perturbations), ``sfl.server_eval`` (the round-start loss), ``sfl.server_tau``
+(the τ SPSA steps), ``sfl.zo_backprop`` (the δ_c pair of server forwards)
+and ``sfl.replay`` (the aggregation). The async steps (core/events.py) and
+GAS (core/baselines.py) use the same five names.
 """
 from __future__ import annotations
 
@@ -61,11 +69,12 @@ def _client_messages(cfg: ModelConfig, sfl: SFLConfig, xc: Params, batch,
                      ukey):
     """Three client forwards -> (h, h+, h-). The perturbation u_m never
     leaves the client; only its key is kept for the later update."""
-    h = client_forward(cfg, xc, batch)
-    hp = client_forward(cfg, zo.perturb(xc, ukey, +sfl.zo_eps,
-                                        sfl.perturbation_dist), batch)
-    hm = client_forward(cfg, zo.perturb(xc, ukey, -sfl.zo_eps,
-                                        sfl.perturbation_dist), batch)
+    with jax.named_scope("sfl.client_forward"):
+        h = client_forward(cfg, xc, batch)
+        hp = client_forward(cfg, zo.perturb(xc, ukey, +sfl.zo_eps,
+                                            sfl.perturbation_dist), batch)
+        hm = client_forward(cfg, zo.perturb(xc, ukey, -sfl.zo_eps,
+                                            sfl.perturbation_dist), batch)
     return h, hp, hm
 
 
@@ -83,8 +92,9 @@ def _server_tau_steps(cfg: ModelConfig, sfl: SFLConfig, xs: Params, h, batch,
             sfl.n_perturbations, sfl.perturbation_dist, replay=replay)
         return sp, (mean_delta, pkeys, coeffs)
 
-    xs_f, (deltas, keys, coeffs) = jax.lax.scan(step, xs,
-                                                jnp.arange(sfl.tau))
+    with jax.named_scope("sfl.server_tau"):
+        xs_f, (deltas, keys, coeffs) = jax.lax.scan(step, xs,
+                                                    jnp.arange(sfl.tau))
     return xs_f, deltas, (keys, coeffs)
 
 
@@ -95,13 +105,16 @@ def _client_round(cfg: ModelConfig, sfl: SFLConfig, xc: Params, xs: Params,
     ukey = jax.random.fold_in(mkey, 0)
     skey = jax.random.fold_in(mkey, 1)
     h, hp, hm = _client_messages(cfg, sfl, xc, batch, ukey)
-    loss0 = (server_forward(cfg, xs, h, batch) if eval_loss
-             else jnp.zeros((), jnp.float32))          # round-start metric
+    with jax.named_scope("sfl.server_eval"):
+        loss0 = (server_forward(cfg, xs, h, batch) if eval_loss
+                 else jnp.zeros((), jnp.float32))      # round-start metric
     xs_f, deltas, records = _server_tau_steps(cfg, sfl, xs, h, batch, skey,
                                               replay)
     # ZO backprop (Eq. 6): scalar from the *final* server model
-    delta_c = (server_forward(cfg, xs_f, hp, batch)
-               - server_forward(cfg, xs_f, hm, batch)).astype(jnp.float32)
+    with jax.named_scope("sfl.zo_backprop"):
+        delta_c = (server_forward(cfg, xs_f, hp, batch)
+                   - server_forward(cfg, xs_f, hm, batch)
+                   ).astype(jnp.float32)
     # client update coeff: η_c · δ_c / (2λ); u replayed from ukey
     ccoeff = sfl.lr_client * delta_c / (2.0 * sfl.zo_eps)
     return {
@@ -139,26 +152,16 @@ def mu_splitfed_round(cfg: ModelConfig, sfl: SFLConfig, params: Params,
         out = jax.vmap(lambda b, k: _client_round(cfg, sfl, xc, xs, b, k,
                                                   eval_loss, replay)
                        )(batches, mkeys)
-        if aggregation == "dense":
-            # Eq. 7: x_s' = x_s + η_g Σ w_m (x_{s,m}^τ − x_s)
-            def agg(g, stacked):
-                delta = jnp.tensordot(w, (stacked - g[None]).astype(jnp.float32),
-                                      axes=1)
-                return (g + sfl.lr_global * delta).astype(g.dtype)
-            xs_new = jax.tree.map(agg, xs, out["xs_final"])
-        else:  # seed_replay: flatten (M, τ, P) records, weight by η_g·w_m
-            xs_new = zo.replay_weighted_records(
-                xs, out["srv_keys"], out["srv_coeffs"], sfl.lr_global * w,
-                sfl.perturbation_dist, impl=replay)
     elif client_mode == "sequential":
         def body(carry, xs_in):
             acc = carry
             b, k, wm = xs_in
             r = _client_round(cfg, sfl, xc, xs, b, k, eval_loss, replay)
             if aggregation == "dense":
-                acc = jax.tree.map(
-                    lambda a, f, g: a + wm * (f - g).astype(jnp.float32),
-                    acc, r["xs_final"], xs)
+                with jax.named_scope("sfl.replay"):
+                    acc = jax.tree.map(
+                        lambda a, f, g: a + wm * (f - g).astype(jnp.float32),
+                        acc, r["xs_final"], xs)
             light = {k2: r[k2] for k2 in
                      ("deltas", "srv_keys", "srv_coeffs", "ukey", "ccoeff",
                       "loss0")}
@@ -166,21 +169,31 @@ def mu_splitfed_round(cfg: ModelConfig, sfl: SFLConfig, params: Params,
         acc0 = (jax.tree.map(lambda g: jnp.zeros(g.shape, jnp.float32), xs)
                 if aggregation == "dense" else jnp.zeros(()))
         acc, out = jax.lax.scan(body, acc0, (batches, mkeys, w))
-        if aggregation == "dense":
-            xs_new = jax.tree.map(
-                lambda g, a: (g + sfl.lr_global * a).astype(g.dtype), xs, acc)
-        else:
-            xs_new = zo.replay_weighted_records(
-                xs, out["srv_keys"], out["srv_coeffs"], sfl.lr_global * w,
-                sfl.perturbation_dist, impl=replay)
     else:
         raise ValueError(client_mode)
 
-    # client aggregation — always replayable (Eq. 7 left): the per-client
-    # update is rank-one in u_m, so Σ_m w_m Δ_m is Σ of replayed records.
-    xc_new = zo.replay_weighted_records(
-        xc, out["ukey"], out["ccoeff"], sfl.lr_global * w,
-        sfl.perturbation_dist, impl=replay)
+    with jax.named_scope("sfl.replay"):
+        if aggregation != "dense":
+            # seed_replay: flatten (M, τ, P) records, weight by η_g·w_m
+            xs_new = zo.replay_weighted_records(
+                xs, out["srv_keys"], out["srv_coeffs"], sfl.lr_global * w,
+                sfl.perturbation_dist, impl=replay)
+        elif client_mode == "parallel":
+            # Eq. 7: x_s' = x_s + η_g Σ w_m (x_{s,m}^τ − x_s)
+            def agg(g, stacked):
+                delta = jnp.tensordot(
+                    w, (stacked - g[None]).astype(jnp.float32), axes=1)
+                return (g + sfl.lr_global * delta).astype(g.dtype)
+            xs_new = jax.tree.map(agg, xs, out["xs_final"])
+        else:
+            xs_new = jax.tree.map(
+                lambda g, a: (g + sfl.lr_global * a).astype(g.dtype), xs, acc)
+        # client aggregation — always replayable (Eq. 7 left): the
+        # per-client update is rank-one in u_m, so Σ_m w_m Δ_m is Σ of
+        # replayed records.
+        xc_new = zo.replay_weighted_records(
+            xc, out["ukey"], out["ccoeff"], sfl.lr_global * w,
+            sfl.perturbation_dist, impl=replay)
 
     metrics = RoundMetrics(loss=out["loss0"], server_deltas=out["deltas"],
                            client_delta=out["ccoeff"])

@@ -13,7 +13,8 @@ and the real engine feed:
   trace       a nestable, thread-safe span tracer (perf_counter) with
               Chrome-trace / JSONL export, near-zero-cost when disabled,
               installed over the engine hot path (chunk dispatch, DES
-              streaming, subset staging, fleet placement).
+              streaming, subset staging, fleet placement); its spans also
+              land in any JAX profiler trace, on the device's clock.
   metrics     a counters/gauges/histograms registry surfaced by
               launch/train.py (--telemetry) and launch/serve.py (stats).
   measure     the (seconds, peak_bytes) perf_counter + tracemalloc
@@ -21,10 +22,11 @@ and the real engine feed:
   runlog      structured JSONL run log (per-round rows + per-chunk
               telemetry), resume-safe (never duplicates rounds).
 
-Nothing here imports jax or the engine: probes are host-side and read at
-chunk boundaries only — the `telemetry-purity` lint rule
-(repro.analysis) enforces that no probe or host-sync coercion lands
-inside a jit-traced body.
+Nothing here imports the engine, nor jax at import time (``trace.span``
+imports jax.profiler on its first call, to ask whether a profiler is
+collecting): probes are host-side and read at chunk boundaries only — the
+`telemetry-purity` lint rule (repro.analysis) enforces that no probe or
+host-sync coercion lands inside a jit-traced body.
 """
 from repro.obs.measure import Measurement, measure
 from repro.obs.metrics import (Counter, Gauge, Histogram, MetricsRegistry,

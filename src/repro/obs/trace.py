@@ -4,8 +4,9 @@ Design constraints, in order:
 
 1. **Near-zero cost when disabled.** Every instrumentation point calls the
    module-level ``span(name, **attrs)``; when no enabled tracer is
-   installed it returns one shared no-op context manager — the cost is a
-   global load, an attribute check, and the kwargs dict Python builds
+   installed and no JAX profiler is collecting it returns one shared no-op
+   context manager — the cost is a global load, an attribute check, one
+   ``TraceAnnotation.is_enabled()`` call, and the kwargs dict Python builds
    anyway. No allocation, no clock read, no lock. The engine's CI overhead
    gate (benchmarks/bench_telemetry.py) holds the *enabled* path to <= 2%
    on the sparse timeline; the disabled path is gated by a unit test.
@@ -17,10 +18,17 @@ Design constraints, in order:
    JSON (load in chrome://tracing or https://ui.perfetto.dev);
    ``export_jsonl`` writes one span per line for ad-hoc processing.
 
+4. **The profiler's clock.** While a JAX profiler is collecting
+   (``jax.profiler.start_trace``), every span also opens a
+   ``jax.profiler.TraceAnnotation`` of the same name and attributes, so
+   the engine's spans lie in the device trace beside the device's ops.
+   jax is imported on the first ``span()`` call, not with this module.
+
 Spans measure HOST time (time.perf_counter). Device work is measured by
 bracketing dispatch with ``jax.block_until_ready`` at chunk boundaries —
 inside jit-traced code a span would fire at trace time only, which is why
-the ``telemetry-purity`` lint rule forbids probes there.
+the ``telemetry-purity`` lint rule forbids probes there (device phases are
+named with ``jax.named_scope`` instead, see core/splitfed.py).
 """
 from __future__ import annotations
 
@@ -59,35 +67,49 @@ _NULL_SPAN = _NullSpan()
 
 
 class _Span:
-    """A live span; created only when the tracer is enabled."""
-    __slots__ = ("_tracer", "name", "attrs", "_t0", "_depth")
+    """A live span; created only when a tracer is enabled or the profiler
+    is collecting. ``tracer`` None: profiler annotation only."""
+    __slots__ = ("_tracer", "name", "attrs", "_t0", "_depth", "_profile",
+                 "_annot")
 
-    def __init__(self, tracer: "SpanTracer", name: str,
-                 attrs: Dict[str, Any]):
+    def __init__(self, tracer: Optional["SpanTracer"], name: str,
+                 attrs: Dict[str, Any], profile: bool = False):
         self._tracer = tracer
         self.name = name
         self.attrs = attrs
+        self._profile = profile
+        self._annot = None
 
     def set(self, **attrs) -> "_Span":
         """Attach attributes discovered mid-span (e.g. bytes staged)."""
         self.attrs.update(attrs)
+        if self._annot is not None:
+            self._annot.set_metadata(**attrs)
         return self
 
     def __enter__(self) -> "_Span":
-        stack = self._tracer._stack()
-        self._depth = len(stack)
-        stack.append(self)
-        self._t0 = time.perf_counter()
+        if self._profile:
+            # a TraceAnnotation starts its clock when it is constructed
+            self._annot = _TraceAnnotation(self.name, **self.attrs)
+            self._annot.__enter__()
+        if self._tracer is not None:
+            stack = self._tracer._stack()
+            self._depth = len(stack)
+            stack.append(self)
+            self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, *exc) -> bool:
-        t1 = time.perf_counter()
         tracer = self._tracer
-        tracer._stack().pop()
-        rec = SpanRecord(self.name, self._t0, t1 - self._t0,
-                         threading.get_ident(), self._depth, self.attrs)
-        with tracer._lock:
-            tracer._records.append(rec)
+        if tracer is not None:
+            t1 = time.perf_counter()
+            tracer._stack().pop()
+            rec = SpanRecord(self.name, self._t0, t1 - self._t0,
+                             threading.get_ident(), self._depth, self.attrs)
+            with tracer._lock:
+                tracer._records.append(rec)
+        if self._annot is not None:
+            self._annot.__exit__(*exc)
         return False
 
 
@@ -148,6 +170,7 @@ class SpanTracer:
 # ---------------------------------------------------------------------------
 
 _ACTIVE: Optional[SpanTracer] = None
+_TraceAnnotation = None     # jax.profiler.TraceAnnotation, on first span()
 
 
 def install(tracer: Optional[SpanTracer]) -> Optional[SpanTracer]:
@@ -164,9 +187,17 @@ def get_tracer() -> Optional[SpanTracer]:
 
 
 def span(name: str, **attrs):
-    """The hot-path probe: ``with span('engine.chunk', r0=r0): ...``.
-    Free when no enabled tracer is installed."""
+    """The hot-path probe: ``with span('engine.chunk', start=r0): ...``.
+    Records into the installed tracer, and writes a TraceAnnotation while
+    the JAX profiler collects. Free when neither is on."""
+    global _TraceAnnotation
+    if _TraceAnnotation is None:
+        from jax.profiler import TraceAnnotation
+        _TraceAnnotation = TraceAnnotation
     t = _ACTIVE
-    if t is None or not t.enabled:
+    if t is not None and not t.enabled:
+        t = None
+    profiling = _TraceAnnotation.is_enabled()
+    if t is None and not profiling:
         return _NULL_SPAN
-    return _Span(t, name, attrs)
+    return _Span(t, name, attrs, profiling)

@@ -35,12 +35,12 @@ def jax_sanitizers(monkeypatch):
     orig_cached_jit = _engine._cached_jit
 
     def guarded_cached_jit(algo, mode, cfg, sfl, build):
-        fn = orig_cached_jit(algo, mode, cfg, sfl, build)
+        fn, built = orig_cached_jit(algo, mode, cfg, sfl, build)
 
         def dispatch(*args, **kwargs):
             with jax.transfer_guard("disallow"):
                 return fn(*args, **kwargs)
-        return dispatch
+        return dispatch, built
 
     monkeypatch.setattr(_engine, "_cached_jit", guarded_cached_jit)
     old = jax.config.jax_numpy_rank_promotion or "allow"

@@ -5,7 +5,10 @@ the engine integration gates: the sim telemetry producer is bit-identical
 to ChunkInfo-derived values (sync AND async), the measured producer
 brackets every chunk, telemetry survives controller re-plans and
 checkpoint resume."""
+import glob
+import itertools
 import json
+import os
 import tempfile
 import threading
 import tracemalloc
@@ -73,16 +76,63 @@ def test_span_nesting_and_export_roundtrip(tmp_path):
 
 
 def test_no_tracer_means_null_span():
-    """With no installed tracer the probe returns ONE shared null object —
-    no allocation, no clock read, nothing recorded."""
+    """With no installed tracer and no profiler collecting the probe
+    returns ONE shared null object — no allocation, no clock read, nothing
+    recorded."""
     prev = install(None)
     try:
+        assert not jax.profiler.TraceAnnotation.is_enabled()
         s1, s2 = span("a", x=1), span("b")
         assert s1 is s2 is _NULL_SPAN
         with s1 as s:
             s.set(anything=0)        # no-op, must not raise
+        tracemalloc.start()
+        try:
+            for _ in range(2):       # the second pass is the one read
+                before = tracemalloc.get_traced_memory()[0]
+                for _ in itertools.repeat(None, 1000):
+                    with span("engine.stage", start=0, stop=2):
+                        pass
+                grown = tracemalloc.get_traced_memory()[0] - before
+        finally:
+            tracemalloc.stop()
+        assert grown == 0
     finally:
         install(prev)
+
+
+def _host_events(trace_dir, prefix):
+    """(name, start_ns, end_ns, stats) of the host events of a profiler
+    trace whose names start with ``prefix``, in time order, read through
+    ProfileData as chipbench/trace_reduce.py reads them."""
+    from jax.profiler import ProfileData
+    (path,) = glob.glob(os.path.join(trace_dir, "**", "*.xplane.pb"),
+                        recursive=True)
+    return sorted((e.name, int(e.start_ns), int(e.start_ns + e.duration_ns),
+                   {k: v for k, v in e.stats})
+                  for plane in ProfileData.from_file(path).planes
+                  if plane.name.startswith("/host:")
+                  for line in plane.lines for e in line.events
+                  if e.name.startswith(prefix))
+
+
+def test_span_writes_a_profiler_annotation(tmp_path):
+    """While the profiler collects, a span also lands in its trace with
+    its attributes (those set mid-span too), and still records into an
+    installed tracer."""
+    tr = SpanTracer()
+    prev = install(tr)
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with span("outer", k=1) as s:
+            s.set(n=2)
+    finally:
+        jax.profiler.stop_trace()
+        install(prev)
+    assert [(r.name, r.attrs) for r in tr.records()] == [
+        ("outer", {"k": 1, "n": 2})]
+    (ev,) = _host_events(str(tmp_path), "outer")
+    assert ev[3] == {"k": 1, "n": 2}
 
 
 def test_disabled_tracer_is_null_and_records_nothing():
@@ -458,6 +508,41 @@ def test_engine_spans_cover_hot_path(setup):
     names = [r.name for r in tr.records()]
     for want in ("engine.stage", "engine.dispatch", "engine.flush"):
         assert names.count(want) == 3, (want, names)
+
+
+def test_engine_spans_reach_the_profiler_trace(setup, tmp_path):
+    """With the JAX profiler on and no tracer installed, a two-chunk scan
+    run writes its host spans into the profiler's trace with their round
+    attributes: prepare, then chunk > stage, dispatch, flush, then
+    finish."""
+    cfg, params, sfl, sched, batch_fn, key = setup
+    prev = install(None)
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0         # as the chip benchmark traces
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        engine.run_rounds("mu_splitfed", cfg, sfl, params, batch_fn, sched,
+                          key, rounds=4, mode="scan", chunk_size=2)
+    finally:
+        jax.profiler.stop_trace()
+        install(prev)
+    ev = {}
+    for name, t0, t1, stats in _host_events(str(tmp_path), "engine."):
+        ev.setdefault(name, []).append((t0, t1, stats))
+    rounds = {n: [(st["start"], st["stop"]) for _, _, st in es]
+              for n, es in ev.items()}
+    assert rounds == {"engine.prepare": [(0, 4)], "engine.finish": [(0, 4)],
+                      **{f"engine.{n}": [(0, 2), (2, 4)] for n in
+                         ("chunk", "stage", "dispatch", "flush")}}
+    assert all("new_program" in st for _, _, st in ev["engine.dispatch"])
+    (p0, p1, _), = ev["engine.prepare"]
+    (f0, f1, _), = ev["engine.finish"]
+    for c0, c1, st in ev["engine.chunk"]:
+        assert p1 <= c0 and c1 <= f0
+        for n in ("engine.stage", "engine.dispatch", "engine.flush"):
+            (s0, s1), = [(a, b) for a, b, x in ev[n]
+                         if x["start"] == st["start"]]
+            assert c0 <= s0 and s1 <= c1, n
 
 
 def test_telemetry_off_emits_nothing(setup):

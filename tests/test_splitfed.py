@@ -1,6 +1,7 @@
 """MU-SplitFed round semantics: mode equivalences, τ=1 == vanilla,
 participation masking, convergence on a tiny task."""
 import dataclasses
+import re
 
 import jax
 import jax.numpy as jnp
@@ -110,3 +111,63 @@ def test_loss_decreases_over_rounds():
         params, m = round_fn(params, jax.random.fold_in(key, r))
         losses.append(float(m.loss.mean()))
     assert (sum(losses[-5:]) / 5) < (sum(losses[:5]) / 5), losses
+
+
+PHASES = ("sfl.client_forward", "sfl.server_eval", "sfl.server_tau",
+          "sfl.zo_backprop", "sfl.replay")
+
+
+@pytest.mark.parametrize("aggregation", ["seed_replay", "dense"])
+@pytest.mark.parametrize("client_mode", ["parallel", "sequential"])
+def test_round_phases_are_named_in_the_compiled_program(setup, client_mode,
+                                                        aggregation):
+    """Each step of Algorithm 1 leaves its named scope in the op_name
+    metadata of the compiled round, which is what a device trace carries."""
+    cfg, params, batches, sfl = setup
+    mask = jnp.ones((M,), jnp.float32)
+    f = jax.jit(lambda p, b, m, k: mu_splitfed_round(
+        cfg, sfl, p, b, m, k, client_mode=client_mode,
+        aggregation=aggregation))
+    hlo = f.lower(params, batches, mask,
+                  jax.random.PRNGKey(7)).compile().as_text()
+    op_names = re.findall(r'op_name="([^"]*)"', hlo)
+    for scope in PHASES:
+        # a path component, or one under a transform: vmap(sfl.replay)
+        under = re.compile(rf"[/(]{re.escape(scope)}[/)]")
+        assert any(under.search(n) for n in op_names), scope
+
+
+def test_compile_cache_keeps_each_programs_scopes(tmp_path, monkeypatch):
+    """Two programs that differ only in a named scope get executables of
+    their own from the persistent cache, so a trace never shows an earlier
+    version's op names."""
+    from jax.experimental.compilation_cache import compilation_cache
+    from repro.launch.compile_cache import enable_compile_cache
+
+    def make(scope):
+        def f(x):
+            with jax.named_scope(scope):
+                return jnp.tanh(x @ x) * 2
+        return f
+
+    keys = ("jax_compilation_cache_dir",
+            "jax_persistent_cache_min_compile_time_secs",
+            "jax_persistent_cache_min_entry_size_bytes",
+            "jax_compilation_cache_include_metadata_in_key")
+    saved = {k: getattr(jax.config, k) for k in keys}
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    try:
+        jax.config.update("jax_compilation_cache_dir", str(tmp_path))
+        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+        jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
+        enable_compile_cache()
+        compilation_cache.reset_cache()
+        x = jnp.ones((8, 8))
+        for scope in ("sfl.server_eval", "sfl.zo_backprop"):
+            hlo = jax.jit(make(scope)).lower(x).compile().as_text()
+            assert f"/{scope}/" in hlo, scope
+        assert len(list(tmp_path.iterdir())) >= 2     # both were cached
+    finally:
+        for k, v in saved.items():
+            jax.config.update(k, v)
+        compilation_cache.reset_cache()
