@@ -1,110 +1,81 @@
-"""Microbenchmark: sequential vs fused batched seed-replay (ladder v3→v4).
+"""Chip microbenchmark: the counter replay of one leaf, Pallas kernel
+against XLA's fusion.
 
-Replays N records (the N = M·τ·P of one seed-replay aggregation) into a
-synthetic parameter tree through both engines:
+Times ``ops.zo_replay_leaf`` with impl='pallas' (the ``zo_replay_flat``
+kernel) and impl='auto' (XLA's fusion, ``kernels/ref.zo_replay_ref``)
+on one parameter leaf, N records at a time, and prints
+one JSON line per (N, impl): milliseconds per call, milliseconds per 10^9
+counter normals drawn, the compile's temporary bytes, and the largest
+difference between the two outputs. It needs a TPU and refuses to run
+without one: a time from another backend says nothing about the chip.
 
-  scan   zo.replay_updates        — lax.scan, one full parameter-sized HBM
-                                    read+write sweep PER RECORD;
-  fused  zo.fused_replay_updates  — all N counter-gaussian contributions
-                                    accumulated per leaf before x is
-                                    touched: one sweep total.
+    python3 benchmarks/bench_replay.py [--shape 22,2048,8192] \\
+        [--n 1,8,32,2048]
 
-Reports wall time and HBM traffic per record, both analytic
-(read+write = 2·4·d bytes per sweep) and as measured on the lowered HLO by
-launch/hlo_analysis (which expands while-loop trip counts — the same
-analysis the perf ladder uses).
-
-    PYTHONPATH=src python -m benchmarks.bench_replay --d 1048576 --n 32
+The default leaf is internlm2-1.8b's stacked server-half MLP weight in
+bfloat16. Lists of up to 64 records are timed over 5 calls after one
+untimed call; a longer list is timed over its first call.
 """
 from __future__ import annotations
 
 import argparse
 import json
+import sys
+import time
+from pathlib import Path
 
-import jax
-import jax.numpy as jnp
-import numpy as np
+sys.path.insert(0, str(Path(__file__).resolve().parent.parent / "src"))
 
-from repro.core import zo
-from repro.launch.hlo_analysis import analyze_compiled
-from repro.obs import measure
+import jax  # noqa: E402
+import jax.numpy as jnp  # noqa: E402
+import numpy as np  # noqa: E402
 
-
-def make_tree(d: int, key):
-    """A few unevenly-shaped f32 leaves totalling ~d elements."""
-    sizes = [d // 2, d // 4, d // 8, d - d // 2 - d // 4 - d // 8]
-    ks = jax.random.split(key, len(sizes))
-    return {f"w{i}": jax.random.normal(k, (max(s, 1),), jnp.float32)
-            for i, (s, k) in enumerate(zip(sizes, ks))}
+from repro.kernels import ops  # noqa: E402
 
 
-def timed(fn, *args, reps=3):
-    """(ms_per_rep, host_peak_bytes) via the shared obs.measure helper —
-    same (seconds, peak_bytes) pair every benchmark row records."""
-    jax.block_until_ready(fn(*args))            # compile
-
-    def body():
-        for _ in range(reps):
-            out = fn(*args)
-        return jax.block_until_ready(out)
-
-    m = measure(body)
-    return m.seconds / reps * 1e3, m.peak_bytes
+def timed(compiled, args, reps: int):
+    """(seconds per call, last output) of an already compiled program;
+    one untimed call first unless a single call is timed."""
+    if reps > 1:
+        jax.block_until_ready(compiled(*args))
+    t0 = time.perf_counter()
+    for _ in range(reps):
+        out = compiled(*args)
+    jax.block_until_ready(out)
+    return (time.perf_counter() - t0) / reps, out
 
 
-def main(argv=None):
-    ap = argparse.ArgumentParser()
-    ap.add_argument("--d", type=int, default=1 << 20,
-                    help="total parameter elements")
-    ap.add_argument("--n", type=int, default=32,
-                    help="records to replay (M·τ·P of one aggregation)")
-    ap.add_argument("--reps", type=int, default=3)
-    ap.add_argument("--out", default="")
+def main(argv=None) -> None:
+    ap = argparse.ArgumentParser(description=__doc__.split("\n")[0])
+    ap.add_argument("--shape", default="22,2048,8192")
+    ap.add_argument("--n", default="1,8,32,2048")
     args = ap.parse_args(argv)
-
-    key = jax.random.PRNGKey(0)
-    params = make_tree(args.d, key)
-    d = sum(x.size for x in jax.tree.leaves(params))
-    keys = jax.vmap(lambda i: jax.random.fold_in(key, i))(jnp.arange(args.n))
-    coeffs = jnp.asarray(
-        (np.random.default_rng(0).normal(size=args.n) * 1e-3
-         ).astype(np.float32))
-
-    scan_fn = jax.jit(lambda p, k, c: zo.replay_updates(p, k, c, "counter"))
-    fused_fn = jax.jit(
-        lambda p, k, c: zo.fused_replay_updates(p, k, c, "counter"))
-
-    # correctness gate before timing
-    diff = max(float(jnp.max(jnp.abs(a - b))) for a, b in zip(
-        jax.tree.leaves(scan_fn(params, keys, coeffs)),
-        jax.tree.leaves(fused_fn(params, keys, coeffs))))
-    assert diff <= 1e-5, f"fused/scan diverge: {diff}"
-
-    rows = {}
-    sweep_bytes = 2 * 4 * d                       # one f32 read+write sweep
-    for name, fn, sweeps in (("scan_v3", scan_fn, args.n),
-                             ("fused_v4", fused_fn, 1)):
-        hlo = analyze_compiled(fn.lower(params, keys, coeffs).compile())
-        ms, peak = timed(fn, params, keys, coeffs, reps=args.reps)
-        rows[name] = {
-            "wall_ms": round(ms, 3),
-            "host_peak_mb": round(peak / 2**20, 3),
-            "analytic_hbm_bytes_per_record": sweep_bytes * sweeps / args.n,
-            "hlo_hbm_bytes_per_record": hlo["expanded_hbm_bytes"] / args.n,
-        }
-    fused_hlo = rows["fused_v4"]["hlo_hbm_bytes_per_record"]
-    report = {"d": d, "n_records": args.n, "max_abs_diff": diff,
-              "per_path": rows,
-              "hbm_reduction_analytic": args.n,   # scan sweeps N×, fused 1×
-              # the HLO parser skips call-wrapped fusion interiors, so tiny
-              # programs can report 0 fused bytes — guard the ratio
-              "hbm_reduction_hlo": (
-                  rows["scan_v3"]["hlo_hbm_bytes_per_record"] / fused_hlo
-                  if fused_hlo > 0 else None)}
-    print(json.dumps(report, indent=1))
-    if args.out:
-        json.dump(report, open(args.out, "w"), indent=1)
-    return report
+    dev = jax.devices()[0]
+    if dev.platform != "tpu":
+        raise SystemExit(f"bench_replay: no TPU (found {dev.platform}); "
+                         f"this benchmark never runs on another platform")
+    shape = tuple(int(s) for s in args.shape.split(","))
+    x = jax.random.normal(jax.random.PRNGKey(0), shape, jnp.bfloat16)
+    for n in (int(s) for s in args.n.split(",")):
+        rng = np.random.default_rng(n)
+        seeds = jnp.asarray(rng.integers(0, 2 ** 32, n, dtype=np.uint32))
+        coeffs = jnp.asarray((rng.normal(size=n) * 1e-3).astype(np.float32))
+        outs = {}
+        for impl in ("pallas", "auto"):
+            fn = jax.jit(lambda x, s, c, impl=impl: ops.zo_replay_leaf(
+                x, s, c, impl=impl, interpret=False))
+            compiled = fn.lower(x, seeds, coeffs).compile()
+            sec, out = timed(compiled, (x, seeds, coeffs),
+                             5 if n <= 64 else 1)
+            outs[impl] = out.astype(jnp.float32)
+            print(json.dumps({
+                "device": dev.device_kind, "shape": list(shape), "n": n,
+                "impl": impl, "ms": sec * 1e3,
+                "ms_per_1e9_draws": sec * 1e3 / (n * x.size / 1e9),
+                "temp_bytes": compiled.memory_analysis().temp_size_in_bytes,
+            }), flush=True)
+        print(json.dumps({"n": n, "max_abs_diff": float(
+            jnp.max(jnp.abs(outs["pallas"] - outs["auto"])))}), flush=True)
 
 
 if __name__ == "__main__":

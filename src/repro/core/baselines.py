@@ -26,7 +26,7 @@ import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig, SFLConfig
 from repro.core import zo
-from repro.core.splitfed import RoundMetrics, _client_round, mu_splitfed_round
+from repro.core.splitfed import RoundMetrics, mu_splitfed_round, split_halves
 from repro.models import (client_forward, loss_fn, merge_params,
                           server_forward, split_params)
 from repro.optim import adamw_init, adamw_update, make_optimizer
@@ -79,7 +79,7 @@ def gas_round(cfg: ModelConfig, sfl: SFLConfig, params: Params, state: GasState,
         raise ValueError(f"gas_round: unsupported aggregation "
                          f"{aggregation!r} (want 'dense' or 'seed_replay')")
     M = sfl.n_clients
-    xc, xs = split_params(cfg, params, sfl.cut_units)
+    xc, xs = split_halves(cfg, params, sfl.cut_units)
     mkeys = jax.vmap(lambda i: jax.random.fold_in(round_key, i))(jnp.arange(M))
 
     def per_client(b_new, b_old, h_old, k, fresh):
@@ -103,7 +103,7 @@ def gas_round(cfg: ModelConfig, sfl: SFLConfig, params: Params, state: GasState,
         with jax.named_scope("sfl.server_tau"):
             sp_new, delta, (skeys, scoeffs) = zo.spsa_step(
                 loss_of, xs, skey, sfl.zo_eps, sfl.lr_server,
-                sfl.n_perturbations, sfl.perturbation_dist, replay=replay)
+                sfl.n_perturbations, sfl.perturbation_dist)
         with jax.named_scope("sfl.zo_backprop"):
             delta_c = (server_forward(cfg, sp_new, hp, b_new)
                        - server_forward(cfg, sp_new, hm, b_new)

@@ -77,8 +77,8 @@ from repro.core.faults import (OUT_CORRUPT, OUT_CRASH, OUT_DELIVER,
                                STALE_LOST, FaultPlan, ResolvedFaults)
 from repro.obs.trace import span
 from repro.core.population import AvailRow
-from repro.core.splitfed import _client_round
-from repro.models import merge_params, split_params
+from repro.core.splitfed import _client_round, split_halves
+from repro.models import merge_params
 
 Params = Any
 
@@ -1198,11 +1198,11 @@ def async_mu_splitfed_step(cfg: ModelConfig, sfl: SFLConfig, params: Params,
     their round_of_origin.
     """
     M = sfl.n_clients
-    xc, xs = split_params(cfg, params, sfl.cut_units)
+    xc, xs = split_halves(cfg, params, sfl.cut_units)
     mkeys = jax.vmap(lambda i: jax.random.fold_in(version_key, i))(
         jnp.arange(M))
     out = jax.vmap(lambda b, k: _client_round(cfg, sfl, xc, xs, b, k,
-                                              eval_loss, replay)
+                                              eval_loss)
                    )(batches, mkeys)
     fresh = {"srv_keys": out["srv_keys"], "srv_coeffs": out["srv_coeffs"],
              "ukey": out["ukey"], "ccoeff": out["ccoeff"],
@@ -1249,11 +1249,11 @@ def async_mu_splitfed_sparse_step(cfg: ModelConfig, sfl: SFLConfig,
     slot (clamped) but carry weight 0, which zeroes their coefficients, so
     they are replay-inert just like the dense path's w=0 rows.
     """
-    xc, xs = split_params(cfg, params, sfl.cut_units)
+    xc, xs = split_halves(cfg, params, sfl.cut_units)
     cid = jnp.clip(start_client, 0, sfl.n_clients - 1)
     mkeys = jax.vmap(lambda i: jax.random.fold_in(version_key, i))(cid)
     out = jax.vmap(lambda b, k: _client_round(cfg, sfl, xc, xs, b, k,
-                                              eval_loss, replay)
+                                              eval_loss)
                    )(batches, mkeys)
     fresh = {"srv_keys": out["srv_keys"], "srv_coeffs": out["srv_coeffs"],
              "ukey": out["ukey"], "ccoeff": out["ccoeff"],

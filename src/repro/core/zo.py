@@ -18,13 +18,18 @@ Replaying a BATCH of N records has two implementations:
                               option for threefry gaussian/sphere noise);
   * ``fused_replay_updates``  one-pass batched replay for dist='counter'
                               (ladder v4): per leaf, all N counter-gaussian
-                              contributions are regenerated and accumulated
-                              locally (in VMEM by the Pallas zo_replay
-                              kernel on TPU, via kernels/ref.py elsewhere)
-                              before x is touched — one HBM read + one
-                              write per leaf regardless of N. This is what
-                              makes seed-replay aggregation O(1) parameter
-                              sweeps instead of O(Mτ P).
+                              contributions are regenerated and summed in
+                              float32 by one XLA fusion
+                              (``kernels/ops.zo_replay_leaf``) before x is
+                              touched — one HBM read + one write per leaf
+                              regardless of N. This is what makes
+                              seed-replay aggregation O(1) parameter sweeps
+                              instead of O(Mτ P).
+
+An SPSA step applies its own P records through ``fused_replay_updates``
+too. The update needs both perturbed losses, so on a full-size v5e program
+XLA gives it a fusion of its own that draws the counter noise again rather
+than holding a float32 copy of the perturbations' draw.
 
 The counter noise stream is layout-unified with the kernels: element with
 row-major linear index n in leaf i draws from
@@ -87,40 +92,24 @@ def tree_noise(key, params: Params, dist: str = "gaussian") -> Params:
     dist='gaussian': iid N(0,1) via threefry (jax.random).
     dist='counter' : iid N(0,1) via the counter-based murmur3+Box-Muller
         generator of kernels/ref.py — ~3 HLO ops/element instead of
-        threefry's long chain; the fused Pallas zo_update kernel applies
-        the same hash family on-chip on TPU (beyond-paper optimization;
-        still an exact SPSA gaussian).
+        threefry's long chain; the Pallas zo_update / zo_replay kernels
+        draw the same stream (beyond-paper optimization; still an exact
+        SPSA gaussian).
     dist='sphere'  : gaussian scaled to ‖u‖=√d globally (the paper's
         √d·S^{d-1}); needs a global norm, hence two passes.
     """
     if dist == "counter":
-        # Sharding-friendly: the (hi, lo) counters are built from
-        # leaf-SHAPED iotas (hi/lo = row-major linear index split at the
-        # kernel LANE), so the whole generator is elementwise in the leaf's
-        # layout and GSPMD partitions it exactly like the parameter it
-        # perturbs — no reshapes, no gathers (the v2 lesson in
-        # EXPERIMENTS.md §Perf). The split at LANE=1024 makes the stream
-        # identical to the (row, lane) layout of the Pallas zo_update /
-        # zo_replay kernels and the kernels/ref.py oracles, which is what
-        # lets fused_replay_updates replay engine-generated records.
-        from repro.kernels.ref import LANE, counter_gauss2
+        # elementwise in each leaf's own layout, so GSPMD partitions the
+        # draw exactly like the parameter it perturbs; the counters split
+        # the row-major index at the kernels' LANE, so the stream is the
+        # (row, lane) one of the Pallas kernels and the kernels/ref.py
+        # oracles, and records replay through fused_replay_updates
+        from repro.kernels.ref import counter_noise
         leaves, treedef = jax.tree.flatten(params)
         base = record_seeds(jnp.asarray(key).reshape(-1))
-        out = []
-        for i, leaf in enumerate(leaves):
-            seed = _leaf_seed(base, i)
-            shape = leaf.shape if leaf.ndim > 0 else (1,)
-            # row-major linear element index, built elementwise
-            lin = jnp.zeros(shape, jnp.uint32)
-            mult = 1
-            for d in range(len(shape) - 1, -1, -1):
-                lin = lin + jax.lax.broadcasted_iota(jnp.uint32, shape, d) \
-                    * jnp.uint32(mult)
-                mult *= shape[d]
-            u = counter_gauss2(seed, lin // jnp.uint32(LANE),
-                               lin % jnp.uint32(LANE))
-            out.append(u.reshape(leaf.shape))
-        return jax.tree.unflatten(treedef, out)
+        return jax.tree.unflatten(
+            treedef, [counter_noise(_leaf_seed(base, i), leaf.shape)
+                      for i, leaf in enumerate(leaves)])
     ks = _leaf_keys(key, params)
     u = jax.tree.map(lambda p, k: jax.random.normal(k, p.shape, jnp.float32),
                      params, ks)
@@ -163,12 +152,11 @@ def fused_replay_updates(params: Params, keys, coeffs,
 
     The seed-replay aggregation hot path (perf-ladder v4). For
     dist='counter', each leaf's N counter-gaussian contributions are
-    regenerated and accumulated locally — in VMEM by the Pallas
-    ``zo_replay_flat`` kernel on TPU, by the ``kernels/ref.py`` oracle
-    elsewhere — before the leaf is touched: one HBM read + one write per
-    leaf regardless of N, versus the N full parameter sweeps of the
-    ``replay_updates`` scan. Equivalent to that scan up to f32 summation
-    order (≤1e-5; see tests/test_replay.py).
+    regenerated and summed in float32 by ``kernels/ops.zo_replay_leaf``
+    (one XLA fusion, windowed past 8 records) before the leaf is touched:
+    one HBM read + one write per leaf regardless of N, versus the N full
+    parameter sweeps of the ``replay_updates`` scan. Equivalent to that
+    scan up to f32 summation order (≤1e-5; see tests/test_replay.py).
 
     dist='gaussian'/'sphere' (threefry noise, not counter-replayable) fall
     back to the sequential scan. impl: 'auto' | 'fused' | 'scan' — 'scan'
@@ -221,13 +209,13 @@ def spsa_delta(loss_of: Callable[[Params], jax.Array], params: Params, key,
 
 def spsa_step(loss_of: Callable[[Params], jax.Array], params: Params, key,
               eps: float, lr, n_perturbations: int = 1,
-              dist: str = "gaussian",
-              replay: str = "auto") -> Tuple[Params, jax.Array, Tuple]:
+              dist: str = "gaussian") -> Tuple[Params, jax.Array, Tuple]:
     """One ZO-SGD step with P-perturbation averaging.
 
     Returns (new_params, mean_delta, records) where records = (keys, coeffs)
-    are the replayable wire format (P entries). ``replay`` selects the
-    record-application path (see fused_replay_updates).
+    are the replayable wire format (P entries). The step applies its
+    records through ``fused_replay_updates``, as the aggregation replays
+    them, so a replay lands on new_params bit for bit.
     """
     P = n_perturbations
     pkeys = jax.vmap(lambda i: jax.random.fold_in(key, i))(jnp.arange(P))
@@ -239,8 +227,7 @@ def spsa_step(loss_of: Callable[[Params], jax.Array], params: Params, key,
 
     deltas = jax.lax.fori_loop(0, P, one, jnp.zeros((P,), jnp.float32))
     coeffs = lr * deltas / (2.0 * eps * P)
-    new_params = fused_replay_updates(params, pkeys, coeffs, dist,
-                                      impl=replay)
+    new_params = fused_replay_updates(params, pkeys, coeffs, dist)
     return new_params, jnp.mean(deltas), (pkeys, coeffs)
 
 

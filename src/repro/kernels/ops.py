@@ -1,4 +1,5 @@
-"""jit'd public wrappers for the Pallas kernels.
+"""jit'd public wrappers for the Pallas kernels. The counter replay of a
+leaf (``zo_replay_leaf``) takes XLA's fusion unless the kernel is asked for.
 
 ``interpret`` defaults to True off-TPU so the same call sites run everywhere
 (CPU containers execute the kernel bodies in interpret mode; TPU compiles
@@ -84,24 +85,22 @@ def zo_perturb_tree(params: Any, seed, eps, *, interpret=None) -> Any:
 REPLAY_SMEM_RECORDS = 2048            # 2048 × 8 B = 16 KiB of SMEM
 
 
-def zo_replay_leaf(x: jnp.ndarray, seeds, coeffs, *, row_offset: int = 0,
-                   impl: str = "auto", interpret=None,
-                   max_records: int = 0) -> jnp.ndarray:
-    """y = x + Σᵢ coeffs[i]·u(seeds[i]) for an arbitrary-shaped leaf —
-    one read + one write of x regardless of N, as long as the (seeds,
-    coeffs) list fits the kernel's SMEM budget. Longer lists (N = M·τ·P
-    past ``REPLAY_SMEM_RECORDS``) are chunked here at the ops layer: each
-    chunk is one fused sweep, so an oversized replay costs ceil(N/bound)
-    parameter sweeps rather than a lowering failure.
+def zo_replay_leaf(x: jnp.ndarray, seeds, coeffs, *, impl: str = "auto",
+                   interpret=None, max_records: int = 0) -> jnp.ndarray:
+    """y = x + Σᵢ coeffs[i]·u(seeds[i]) for an arbitrary-shaped leaf, with
+    one read and one write of x regardless of N.
 
-    impl='auto' picks the compiled Pallas kernel on TPU and the pure-JAX
-    reference elsewhere (an interpret-mode Pallas sweep over N records is
-    needlessly slow on CPU); 'pallas'/'ref' force a backend for the
-    equivalence tests. ``max_records`` overrides the SMEM bound (tests)."""
+    impl='auto' sums the records in float32 in one XLA fusion
+    (``kernels/ref.zo_replay_ref``), in the leaf's own layout, windowed
+    past 8 records; on a v5e it draws counter noise 1.3 to 1.7 times as
+    fast as the Pallas kernel at every N from 1 to 2,048
+    (``benchmarks/bench_replay.py``). impl='pallas' runs the
+    ``zo_replay_flat`` kernel over the leaf padded to whole
+    (BLOCK_ROWS, LANE) blocks; its (seeds, coeffs) list lives in SMEM, so
+    a list longer than ``REPLAY_SMEM_RECORDS`` (``max_records`` overrides
+    it in tests) is chunked here, one kernel sweep per chunk."""
     if impl == "auto":
-        impl = "pallas" if on_tpu() else "ref"
-    if impl == "ref":
-        return _ref.zo_replay_ref(x, seeds, coeffs, row_offset=row_offset)
+        return _ref.zo_replay_ref(x, seeds, coeffs)
     assert impl == "pallas", impl
     interpret = _auto_interpret(interpret)
     seeds = jnp.asarray(seeds, jnp.uint32).reshape(-1)
@@ -116,7 +115,7 @@ def zo_replay_leaf(x: jnp.ndarray, seeds, coeffs, *, row_offset: int = 0,
     flat = jnp.pad(x.reshape(-1), (0, rows * LANE - n)).reshape(rows, LANE)
     for i in range(0, seeds.shape[0], bound):
         flat = zo_replay_flat(flat, seeds[i:i + bound], coeffs[i:i + bound],
-                              offset=row_offset, interpret=interpret)
+                              interpret=interpret)
     return flat.reshape(-1)[:n].reshape(x.shape)
 
 

@@ -2,6 +2,8 @@
 for the interpret-mode sweeps in tests/test_kernels.py)."""
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -68,6 +70,29 @@ def noise_rows(seed, row0: int, n_rows: int) -> jnp.ndarray:
                           jnp.broadcast_to(lo, (n_rows, LANE)))
 
 
+@functools.partial(jax.jit, static_argnums=1)
+def counter_noise(seed, shape) -> jnp.ndarray:
+    """The (row, LANE) stream of ``noise_rows`` drawn in a leaf's own
+    layout: the element with row-major linear index n draws
+    ``counter_gauss2(seed, n // LANE, n % LANE)``. The
+    counters come from leaf-shaped iotas, so the draw is elementwise in
+    the leaf's layout (no reshape, no padding) and partitions like the
+    leaf it perturbs.
+
+    Jitted so that a program lowers the draw once per leaf shape and
+    calls it at each use (a round draws up to 8 records per leaf); XLA
+    inlines the calls before it fuses, so the compiled program is the
+    same. ``shape`` is static and must be a tuple."""
+    lin = jnp.zeros(shape, jnp.uint32)
+    mult = 1
+    for d in range(len(shape) - 1, -1, -1):
+        lin = lin + jax.lax.broadcasted_iota(jnp.uint32, shape, d) \
+            * jnp.uint32(mult)
+        mult *= shape[d]
+    return counter_gauss2(seed, lin // jnp.uint32(LANE),
+                          lin % jnp.uint32(LANE))
+
+
 # ---------------------------------------------------------------------------
 # zo_update oracle: y = x + coeff * u over the (row, LANE) counter layout
 # ---------------------------------------------------------------------------
@@ -92,30 +117,27 @@ def zo_update_ref(x: jnp.ndarray, seed, coeff, row_offset: int = 0
 _REPLAY_WINDOW = 8
 
 
-def zo_replay_ref(x: jnp.ndarray, seeds, coeffs, row_offset: int = 0
-                  ) -> jnp.ndarray:
-    """Batched-replay oracle: y = x + Σᵢ coeffs[i]·u(seeds[i]).
+def zo_replay_ref(x: jnp.ndarray, seeds, coeffs) -> jnp.ndarray:
+    """Batched replay: y = x + Σᵢ coeffs[i]·u(seeds[i]).
 
     Matches zo_replay_flat (and N sequential zo_update_ref applications up
     to f32 summation order): the Σ cᵢ·uᵢ accumulator is built elementwise
-    BEFORE x is touched, so the parameter leaf is read and written exactly
-    once regardless of N. Above _REPLAY_WINDOW records the accumulation
-    runs as a scan of WINDOW-record unrolled windows (records padded with
+    in float32 BEFORE x is touched, so the parameter leaf is read and
+    written exactly once regardless of N, and rounded once. The noise is
+    drawn in the leaf's own layout (``counter_noise``): on a TPU, the
+    kernel's (rows, LANE) layout would cost a relayout copy of the leaf
+    and its counters. Up to _REPLAY_WINDOW records are one fusion with no
+    temporary; above that the accumulation runs as a scan of WINDOW-record
+    unrolled windows over one float32 accumulator (records padded with
     zero coefficients to a whole window)."""
     seeds = jnp.asarray(seeds, jnp.uint32).reshape(-1)
     coeffs = jnp.asarray(coeffs, jnp.float32).reshape(-1)
     n = seeds.shape[0]
-    n_el = x.size
-    rows = -(-n_el // LANE)
-    hi = ((jnp.arange(rows, dtype=jnp.uint32) + jnp.uint32(row_offset))
-          [:, None] + jnp.zeros((rows, LANE), jnp.uint32))
-    lo = jnp.broadcast_to(jnp.arange(LANE, dtype=jnp.uint32)[None, :],
-                          (rows, LANE))
     W = _REPLAY_WINDOW
     if n <= W:
-        acc = jnp.zeros((rows, LANE), jnp.float32)
+        acc = jnp.zeros(x.shape, jnp.float32)
         for i in range(n):
-            acc = acc + coeffs[i] * counter_gauss2(seeds[i], hi, lo)
+            acc = acc + coeffs[i] * counter_noise(seeds[i], x.shape)
     else:
         pad = (-n) % W                 # zero-coeff records contribute +0
         gs = jnp.pad(seeds, (0, pad)).reshape(-1, W)
@@ -124,12 +146,11 @@ def zo_replay_ref(x: jnp.ndarray, seeds, coeffs, row_offset: int = 0
         def body(acc, sc):
             s, c = sc
             for j in range(W):
-                acc = acc + c[j] * counter_gauss2(s[j], hi, lo)
+                acc = acc + c[j] * counter_noise(s[j], x.shape)
             return acc, None
 
-        acc, _ = jax.lax.scan(body, jnp.zeros((rows, LANE), jnp.float32),
+        acc, _ = jax.lax.scan(body, jnp.zeros(x.shape, jnp.float32),
                               (gs, gc))
-    acc = acc.reshape(-1)[:n_el].reshape(x.shape)
     return (x.astype(jnp.float32) + acc).astype(x.dtype)
 
 

@@ -81,3 +81,23 @@ def test_zo_update_flat_compiles_for_v5e(one_chip):
                         ((rows, LANE), jnp.bfloat16),
                         ((), jnp.uint32), ((), jnp.float32))
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_counter_replay_compiles_without_the_kernel_for_v5e(one_chip,
+                                                            monkeypatch):
+    """The seed-replay aggregation of counter records at an internlm2-1.8b
+    leaf (the stacked server-half MLP weight) is XLA's fusion: no Mosaic
+    kernel in the program, and no float32 copy of the leaf held."""
+    import repro.kernels.ops as ops
+    from repro.core import zo
+    monkeypatch.setattr(ops, "on_tpu", lambda: True)
+    shape, n = (22, 2048, 8192), 8
+
+    def replay(x, keys, coeffs):
+        return zo.fused_replay_updates({"w": x}, keys, coeffs,
+                                       dist="counter")["w"]
+    compiled = _compile(replay, one_chip, (shape, jnp.bfloat16),
+                        ((n, 2), jnp.uint32), ((n,), jnp.float32))
+    assert "tpu_custom_call" not in compiled.as_text()
+    f32_copy = 4 * shape[0] * shape[1] * shape[2]
+    assert compiled.memory_analysis().temp_size_in_bytes < f32_copy

@@ -58,7 +58,7 @@ def test_zo_replay_leaf_pallas_equals_ref_padded():
     x = jax.random.normal(jax.random.PRNGKey(2), (37, 11), jnp.float32)
     seeds, coeffs = _records(8, salt=2)
     a = zo_replay_leaf(x, seeds, coeffs, impl="pallas", interpret=True)
-    b = zo_replay_leaf(x, seeds, coeffs, impl="ref")
+    b = zo_replay_leaf(x, seeds, coeffs, impl="auto")
     assert a.shape == x.shape
     assert float(jnp.max(jnp.abs(a - b))) <= 1e-5
 
@@ -101,7 +101,7 @@ def test_zo_replay_leaf_chunks_past_smem_bound():
     seeds, coeffs = _records(13, salt=13)
     chunked = zo_replay_leaf(x, seeds, coeffs, impl="pallas",
                              interpret=True, max_records=4)
-    want = zo_replay_leaf(x, seeds, coeffs, impl="ref")
+    want = zo_replay_leaf(x, seeds, coeffs, impl="auto")
     assert float(jnp.max(jnp.abs(chunked - want))) <= 1e-5
 
 
@@ -171,6 +171,79 @@ def test_spsa_step_records_replay_through_fused_path():
                                             1e-3, 0.1, 3, dist="counter")
     replayed = zo.fused_replay_updates(params, keys, coeffs, dist="counter")
     assert maxdiff(new_p, replayed) == 0.0
+
+
+def _rows_replay(params, keys, coeffs):
+    """x − Σ_j c_j·u_j per leaf with the noise drawn in the (rows, LANE)
+    layout of ``ref.noise_rows``, summed in f32 and rounded once: what the
+    Pallas kernel and its oracle compute, independent of tree_noise."""
+    seeds = zo.record_seeds(keys)
+    leaves, treedef = jax.tree.flatten(params)
+    out = []
+    for i, x in enumerate(leaves):
+        rows = -(-x.size // LANE)
+        acc = jnp.zeros(x.shape, jnp.float32)
+        for j in range(seeds.shape[0]):
+            u = ref.noise_rows(zo._leaf_seed(seeds[j], i), 0, rows)
+            acc = acc + -coeffs[j] * u.reshape(-1)[:x.size].reshape(x.shape)
+        out.append((x.astype(jnp.float32) + acc).astype(x.dtype))
+    return jax.tree.unflatten(treedef, out)
+
+
+def _quad(p):
+    return sum(jnp.sum(jnp.square(x.astype(jnp.float32)))
+               for x in jax.tree.leaves(p))
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_counter_spsa_step_applies_its_record_as_the_kernel_does(dtype):
+    """P = 1: the step's update, drawn in leaf layout by counter_noise, lands
+    on the parameters the kernel's (rows, LANE) replay of the same record
+    gives, bit for bit."""
+    params = jax.tree.map(lambda x: x.astype(dtype),
+                          _tree(jax.random.PRNGKey(14)))
+    new_p, _, (keys, coeffs) = zo.spsa_step(_quad, params,
+                                            jax.random.PRNGKey(15),
+                                            1e-3, 0.1, 1, dist="counter")
+    assert maxdiff(new_p, params) > 0
+    assert maxdiff(new_p, _rows_replay(params, keys, coeffs)) == 0.0
+
+
+def test_counter_spsa_step_sums_its_records_before_rounding():
+    """P = 2: one f32 sum of both records, rounded once."""
+    params = _tree(jax.random.PRNGKey(16))
+    new_p, _, (keys, coeffs) = zo.spsa_step(_quad, params,
+                                            jax.random.PRNGKey(17),
+                                            1e-3, 0.1, 2, dist="counter")
+    want = _rows_replay(params, keys, coeffs)
+    scale = max(float(jnp.max(jnp.abs(x))) for x in jax.tree.leaves(want))
+    assert maxdiff(new_p, want) <= 1e-6 * scale
+
+
+@pytest.mark.parametrize("n", [1, 8, 2048])
+def test_zo_replay_leaf_auto_takes_the_xla_formula_on_tpu(monkeypatch, n):
+    """impl='auto' sums the records in XLA's fusion and never reaches the
+    Pallas kernel, on a TPU backend too, at any N."""
+    import repro.kernels.ops as ops
+
+    def no_kernel(*a, **k):
+        raise AssertionError("zo_replay_flat reached on the auto path")
+    monkeypatch.setattr(ops, "on_tpu", lambda: True)
+    monkeypatch.setattr(ops, "zo_replay_flat", no_kernel)
+    x = jax.random.normal(jax.random.PRNGKey(19), (5, 300), jnp.bfloat16)
+    seeds, coeffs = _records(n, salt=19)
+    got = ops.zo_replay_leaf(x, seeds, coeffs)
+    assert maxdiff(got, ref.zo_replay_ref(x, seeds, coeffs)) == 0.0
+
+
+def test_counter_replay_lowers_the_draw_once_per_leaf_shape():
+    """Eight records into one leaf lower one copy of the counter draw and
+    call it eight times; a copy per record made the lowered chunk program
+    of internlm2-1.8b.silo 3.5 times as large."""
+    x = jnp.zeros((5, 300), jnp.bfloat16)
+    seeds, coeffs = _records(8, salt=20)
+    text = jax.jit(ref.zo_replay_ref).lower(x, seeds, coeffs).as_text()
+    assert text.count("stablehlo.cosine") == 1
 
 
 # ---------------------------------------------------------------------------

@@ -137,6 +137,32 @@ def test_round_phases_are_named_in_the_compiled_program(setup, client_mode,
         assert any(under.search(n) for n in op_names), scope
 
 
+@pytest.mark.parametrize("kind", ["sync", "async", "async_sparse", "gas"])
+def test_every_round_splits_behind_a_barrier(setup, kind):
+    """Each round that splits the tree, runs its clients and replays into
+    the halves splits through split_halves, whose optimization barrier
+    keeps XLA from holding the whole tree live beside the halves."""
+    from repro.core import events
+    from repro.core.baselines import gas_init_state, gas_round
+    cfg, params, batches, sfl = setup
+    ones, key = jnp.ones((M,), jnp.float32), jax.random.PRNGKey(7)
+    if kind == "sync":
+        fn = lambda p: mu_splitfed_round(cfg, sfl, p, batches, ones, key)
+    elif kind == "async":
+        store = events.init_store(sfl)
+        fn = lambda p: events.async_mu_splitfed_step(
+            cfg, sfl, p, store, batches, ones, ones / M, key)
+    elif kind == "async_sparse":
+        sp = dataclasses.replace(sfl, timeline="sparse")
+        store, ids = events.init_store(sp), jnp.arange(M)
+        fn = lambda p: events.async_mu_splitfed_sparse_step(
+            cfg, sp, p, store, batches, ids, ids, ids, ones / M, key)
+    else:
+        state = gas_init_state(cfg, sfl, params, batches)
+        fn = lambda p: gas_round(cfg, sfl, p, state, batches, ones, key)
+    assert "optimization_barrier" in str(jax.make_jaxpr(fn)(params))
+
+
 def test_compile_cache_keeps_each_programs_scopes(tmp_path, monkeypatch):
     """Two programs that differ only in a named scope get executables of
     their own from the persistent cache, so a trace never shows an earlier
